@@ -1,0 +1,18 @@
+"""The check fails a run of the one-RSU cell with each planted fault."""
+import time
+
+import pytest
+
+import faults
+import harness
+import tiny
+
+CELL = "r18-single.paper-b256"
+
+
+@pytest.mark.parametrize("fault", ["unchanged", "half_batch"])
+def test_planted_fault_is_not_correct(fault, monkeypatch, tmp_path):
+    tiny.shrink(monkeypatch, tmp_path)
+    faults.plant(monkeypatch, fault)
+    result = harness.run_cell(CELL, 7, 0.1, False, t0=time.perf_counter())
+    assert result["correct"] is False, result["checks"]
