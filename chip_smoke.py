@@ -202,19 +202,9 @@ def one_chip(devs):
     hist_e = [rec0] + hist_e
 
     # compiled campaign from the same state
-    t = time.perf_counter()
     st_c, hist_c = run_campaign(sc, state0, rounds=ROUNDS)
-    jax.block_until_ready(st_c.global_tree)
-    print(f"run_campaign {ROUNDS} rounds (scan; program from the compile "
-          f"cache): {time.perf_counter() - t:.2f} s", flush=True)
-    t = time.perf_counter()
-    st_2, hist_2 = run_campaign(sc, st_c, rounds=ROUNDS)
-    jax.block_until_ready(st_2.global_tree)
-    dt = time.perf_counter() - t
-    print(f"steady round: {dt / ROUNDS:.4f} s ({ROUNDS} rounds in "
-          f"{dt:.3f} s, host planning included)", flush=True)
 
-    for name, hist in (("eager", hist_e), ("campaign", hist_c + hist_2)):
+    for name, hist in (("eager", hist_e), ("campaign", hist_c)):
         _assert_finite([r["loss"] for r in hist], name)
         print(f"{name} losses: {[r['loss'] for r in hist]}", flush=True)
     for re_, rc in zip(hist_e, hist_c):
@@ -304,11 +294,8 @@ def four_chips(devs):
 
     results = {}
     for name, sc in (("sharded", sc_m), ("host", sc_h)):
-        t = time.perf_counter()
         st, hist = run_campaign(sc, rounds=ROUNDS_4)
-        jax.block_until_ready(st.global_tree)
-        print(f"{name}: run_campaign {ROUNDS_4} rounds (compile included) "
-              f"{time.perf_counter() - t:.2f} s, losses "
+        print(f"{name}: run_campaign {ROUNDS_4} rounds, losses "
               f"{[r['loss'] for r in hist]}", flush=True)
         _assert_finite([r["loss"] for r in hist], name)
         results[name] = (st, hist)

@@ -177,12 +177,14 @@ def _delta_encode(stacked, base, ef=None, stacked_base=False):
     complement), so decode's add undoes encode's subtract bit for bit,
     with no float rounding anywhere — exact for ANY values. Leafwise
     broadcasting handles single and stacked bases alike."""
-    return {"delta": jax.tree.map(_delta_enc_leaf, stacked, base)}, None
+    with jax.named_scope("codec"):
+        return {"delta": jax.tree.map(_delta_enc_leaf, stacked, base)}, None
 
 
 def _delta_decode(payload, base, stacked_base=False):
-    return jax.tree.map(lambda d, b: _delta_dec_leaf(d, b),
-                        payload["delta"], base)
+    with jax.named_scope("codec"):
+        return jax.tree.map(lambda d, b: _delta_dec_leaf(d, b),
+                            payload["delta"], base)
 
 
 def _q8_delta_rows(stacked, base):
@@ -205,29 +207,32 @@ def _q8_delta_rows(stacked, base):
 
 def _int8_encode(stacked, base, ef=None, stacked_base=False):
     from repro.kernels import ops as _kops   # deferred: keep comms light
-    flat = _q8_delta_rows(stacked, base)
-    if ef is None:
-        ef = jnp.zeros_like(flat)
-    codes, scales, new_ef = _kops.q8_encode_flat(flat, ef,
-                                                 backend=_q8_backend)
-    return {"codes": codes, "scales": scales}, new_ef
+    with jax.named_scope("codec"):
+        flat = _q8_delta_rows(stacked, base)
+        if ef is None:
+            ef = jnp.zeros_like(flat)
+        codes, scales, new_ef = _kops.q8_encode_flat(flat, ef,
+                                                     backend=_q8_backend)
+        return {"codes": codes, "scales": scales}, new_ef
 
 
 def _int8_decode(payload, base, stacked_base=False):
     from repro.kernels import ops as _kops
-    flat = _kops.q8_decode_flat(payload["codes"], payload["scales"],
-                                backend=_q8_backend)
-    leaves, treedef = jax.tree.flatten(base)
-    # stacked_base says whether `base` carries the per-row leading axis
-    # (the handover download: each row's base is its RSU's model) — the
-    # caller knows, guessing from shapes is ambiguous for small trees
-    row_shapes = [tuple(l.shape[1:]) if stacked_base else tuple(l.shape)
-                  for l in leaves]
-    delta = _unravel_rows(flat, row_shapes, treedef)
-    return jax.tree.map(
-        lambda b, d: (jnp.broadcast_to(b, d.shape).astype(jnp.float32)
-                      + d).astype(b.dtype),
-        base, delta)
+    with jax.named_scope("codec"):
+        flat = _kops.q8_decode_flat(payload["codes"], payload["scales"],
+                                    backend=_q8_backend)
+        leaves, treedef = jax.tree.flatten(base)
+        # stacked_base says whether `base` carries the per-row leading
+        # axis (the handover download: each row's base is its RSU's
+        # model) — the caller knows, guessing from shapes is ambiguous
+        # for small trees
+        row_shapes = [tuple(l.shape[1:]) if stacked_base
+                      else tuple(l.shape) for l in leaves]
+        delta = _unravel_rows(flat, row_shapes, treedef)
+        return jax.tree.map(
+            lambda b, d: (jnp.broadcast_to(b, d.shape).astype(jnp.float32)
+                          + d).astype(b.dtype),
+            base, delta)
 
 
 def _no_state(cfg, tree):

@@ -96,22 +96,23 @@ def _weighted_stacked_sum(stacked, weights, mask=None) -> object:
     (w*1.0 == w and w*0.0 == 0.0, so a masked padded sum is bit-exact
     versus the unpadded sum over the valid prefix).
     """
-    # analysis: allow=retrace-fresh-array -- f32 normalization at the
-    # aggregation boundary (no-op for device weights, traced in jit)
-    weights = jnp.asarray(weights, jnp.float32)
-    backend = _resolve_wagg_backend()
-    if backend != "tree":
-        from repro.kernels import ops as _kops  # deferred: keep core import-light
-        return _kops.wagg_stacked(stacked, weights, mask=mask,
-                                  interpret=(backend == "interpret"))
+    with jax.named_scope("aggregate"):
+        # analysis: allow=retrace-fresh-array -- f32 normalization at the
+        # aggregation boundary (no-op for device weights, traced in jit)
+        weights = jnp.asarray(weights, jnp.float32)
+        backend = _resolve_wagg_backend()
+        if backend != "tree":
+            from repro.kernels import ops as _kops  # deferred: keep core import-light
+            return _kops.wagg_stacked(stacked, weights, mask=mask,
+                                      interpret=(backend == "interpret"))
 
-    if mask is not None:
-        # analysis: allow=retrace-fresh-array -- same normalization
-        weights = weights * jnp.asarray(mask, jnp.float32)
+        if mask is not None:
+            # analysis: allow=retrace-fresh-array -- same normalization
+            weights = weights * jnp.asarray(mask, jnp.float32)
 
-    return jax.tree.map(
-        lambda leaf: row_weighted_sum(weights, leaf).astype(leaf.dtype),
-        stacked)
+        return jax.tree.map(
+            lambda leaf: row_weighted_sum(weights, leaf).astype(leaf.dtype),
+            stacked)
 
 
 # rows summed per unrolled block of `row_weighted_sum`
@@ -317,7 +318,8 @@ SCHEME_WEIGHTS = {
 
 def _make_dispatch(weight_fn):
     def dispatch(cohort, cfg):
-        return cohort_weighted_sum(cohort, weight_fn(cohort, cfg))
+        with jax.named_scope("aggregate"):
+            return cohort_weighted_sum(cohort, weight_fn(cohort, cfg))
     return dispatch
 
 

@@ -73,23 +73,24 @@ def make_local_train_step(cfg: FLConfig):
         pessimizes convolutions inside while-loops (~25x slower measured),
         and local_iters is 1-2 in the paper.
         """
-        opt_state = opt_init(tree["params"])
-        losses = []
-        for k in jax.random.split(key, cfg.local_iters):
-            tree_c = tree
+        with jax.named_scope("client_step"):
+            opt_state = opt_init(tree["params"])
+            losses = []
+            for k in jax.random.split(key, cfg.local_iters):
+                tree_c = tree
 
-            def loss_fn(params):
-                t = {"params": params, "state": tree_c["state"]}
-                loss, t2 = _client_loss(t, cfg, images, k)
-                return loss, t2["state"]
+                def loss_fn(params):
+                    t = {"params": params, "state": tree_c["state"]}
+                    loss, t2 = _client_loss(t, cfg, images, k)
+                    return loss, t2["state"]
 
-            (loss, new_state), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(tree_c["params"])
-            new_params, opt_state = opt_update(tree_c["params"], grads,
-                                               opt_state, lr)
-            tree = {"params": new_params, "state": new_state}
-            losses.append(loss)
-        return tree, jnp.stack(losses).mean()
+                (loss, new_state), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(tree_c["params"])
+                new_params, opt_state = opt_update(tree_c["params"], grads,
+                                                   opt_state, lr)
+                tree = {"params": new_params, "state": new_state}
+                losses.append(loss)
+            return tree, jnp.stack(losses).mean()
 
     return local_train
 
@@ -100,33 +101,35 @@ def make_moco_local_train_step(cfg: FLConfig):
 
     def local_train(tree, key_tree, queue, images, key, lr):
         # python unroll (see make_local_train_step for the XLA-CPU rationale)
-        opt_state = opt_init(tree["params"])
-        losses, kvec = [], None
-        for k in jax.random.split(key, cfg.local_iters):
-            k1, k2 = jax.random.split(k)
-            v1 = ssl.pi1(k1, images)
-            v2 = ssl.pi2(k2, images)
-            tree_c, key_tree_c = tree, key_tree
+        with jax.named_scope("client_step"):
+            opt_state = opt_init(tree["params"])
+            losses, kvec = [], None
+            for k in jax.random.split(key, cfg.local_iters):
+                k1, k2 = jax.random.split(k)
+                v1 = ssl.pi1(k1, images)
+                v2 = ssl.pi2(k2, images)
+                tree_c, key_tree_c = tree, key_tree
 
-            def loss_fn(params):
-                t = {"params": params, "state": tree_c["state"]}
-                q, _, t2 = resnet_apply(t, v1, train=True)
-                kv, _, _ = resnet_apply(key_tree_c, v2, train=False)
-                kv = jax.lax.stop_gradient(kv)
-                return info_nce_loss(q, kv, queue), (t2["state"], kv)
+                def loss_fn(params):
+                    t = {"params": params, "state": tree_c["state"]}
+                    q, _, t2 = resnet_apply(t, v1, train=True)
+                    kv, _, _ = resnet_apply(key_tree_c, v2, train=False)
+                    kv = jax.lax.stop_gradient(kv)
+                    return info_nce_loss(q, kv, queue), (t2["state"], kv)
 
-            (loss, (new_state, kvec)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(tree_c["params"])
-            new_params, opt_state = opt_update(tree_c["params"], grads,
-                                               opt_state, lr)
-            tree = {"params": new_params, "state": new_state}
-            key_tree = {
-                "params": ssl.momentum_update(key_tree_c["params"], new_params,
-                                              cfg.moco_momentum),
-                "state": new_state,
-            }
-            losses.append(loss)
-        return tree, key_tree, kvec, jnp.stack(losses).mean()
+                (loss, (new_state, kvec)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(tree_c["params"])
+                new_params, opt_state = opt_update(tree_c["params"], grads,
+                                                   opt_state, lr)
+                tree = {"params": new_params, "state": new_state}
+                key_tree = {
+                    "params": ssl.momentum_update(key_tree_c["params"],
+                                                  new_params,
+                                                  cfg.moco_momentum),
+                    "state": new_state,
+                }
+                losses.append(loss)
+            return tree, key_tree, kvec, jnp.stack(losses).mean()
 
     return local_train
 

@@ -73,8 +73,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
-from repro.comms.codecs import CODECS
+from repro.comms.codecs import CODECS, tree_nbytes
 from repro.core import aggregation as agg
 from repro.core.clients import raw_local_step
 from repro.core.cohort import CohortBatch
@@ -394,12 +395,14 @@ def _build_handover_body(scenario):
         rstack = jax.tree.map(keep, rstack, up_stack)
         # region sync: merge with the precomputed level-2 weights when
         # the flag is set, else pass both models through unchanged
-        merged = agg._weighted_stacked_sum(rstack, sync_w)
-        rstack = jax.tree.map(
-            lambda r_, m: jnp.where(sync, jnp.broadcast_to(m, r_.shape), r_),
-            rstack, merged)
-        gtree = jax.tree.map(lambda g, m: jnp.where(sync, m, g),
-                             gtree, merged)
+        with jax.named_scope("region_sync"):
+            merged = agg._weighted_stacked_sum(rstack, sync_w)
+            rstack = jax.tree.map(
+                lambda r_, m: jnp.where(sync, jnp.broadcast_to(m, r_.shape),
+                                        r_),
+                rstack, merged)
+            gtree = jax.tree.map(lambda g, m: jnp.where(sync, m, g),
+                                 gtree, merged)
         if new_ef is not None:
             gtree, rstack, new_ef = jax.lax.optimization_barrier(
                 (gtree, rstack, new_ef))
@@ -586,6 +589,13 @@ def run_campaign(scenario, state: Optional[FLState] = None,
     publish_every     chunk size when only serving cadence matters —
                       like log_every/checkpoint_every but for the
                       publish hook; 0 publishes once per natural chunk
+
+    Under a `jax.profiler` trace the call marks its pieces with host
+    spans: `campaign.data_stack` once, and per chunk a `campaign.chunk`
+    step (`step_num` = the chunk's first round) holding `campaign.plan`,
+    `.carry`, `.dispatch`, `.fetch`, `.state`, `.publish` and
+    `.checkpoint`, each with `round` = that step number (README
+    §Tracing a campaign).
     """
     check_campaign_supported(scenario)
     mode = resolve_mode(mode)
@@ -604,48 +614,68 @@ def run_campaign(scenario, state: Optional[FLState] = None,
     chunk = max(1, min(chunk, total)) if total else 1
     fns = campaign_callables(scenario)
     rep = _replicated(scenario)
-    dstack = _data_stack(scenario)
-    if rep is not None:
-        dstack = jax.device_put(dstack, rep)
+    # host spans (jax.profiler): free unless a profiler is collecting;
+    # their attributes come from shapes, never from a device sync
+    with TraceAnnotation("campaign.data_stack") as span:
+        dstack = _data_stack(scenario)
+        if rep is not None:
+            dstack = jax.device_put(dstack, rep)
+        span.set_metadata(bytes=dstack.nbytes)
     history = []
     done = 0
     while done < total:
         k = min(chunk, total - done)
-        xs_list, recs, key, rng, topo_host = _plan_chunk(state, scenario, k)
-        carry = _carry_of(state, scenario)
-        if rep is not None:
-            carry = jax.device_put(carry, rep)
+        rnd = state.round
         if transfer_guard:
             from repro.analysis.guards import no_implicit_transfers
             guard = no_implicit_transfers()
         else:
             guard = contextlib.nullcontext()
-        with guard:
-            if mode == "scan":
-                xs = jax.tree.map(lambda *ls: jnp.stack(ls), *xs_list)
-                carry, ys = fns["scan"](dstack, carry, xs)
-            else:
-                ys = []
-                for x in xs_list:
-                    carry, losses = fns["jit_round"](dstack, carry, x)
-                    ys.append(losses)
-                ys = jnp.stack(ys)
-        # ONE host transfer per chunk: the stacked loss history
-        # analysis: sanctioned-sync -- the designed once-per-chunk fetch
-        losses_h = np.asarray(jax.device_get(ys), np.float64)
-        for i, rec in enumerate(recs):
-            rec["loss"] = float(np.mean(losses_h[i]))
-            history.append(rec)
-            if log_every and rec["round"] % log_every == 0:
-                print(f"[round {rec['round']:4d}] loss={rec['loss']:.4f} "
-                      f"lr={rec['lr']:.4f}")
-        state = _state_of(carry, state, scenario, key, rng, k, topo_host)
-        if publish is not None:
-            publish(state.round, state.global_tree)
-        done += k
-        if checkpoint_every:
-            from repro.checkpoint.store import save_state
-            save_state(os.path.join(checkpoint_dir,
-                                    f"round_{state.round:06d}"),
-                       state, scenario)
+        # one step per chunk, numbered by its first round; every child
+        # span carries the same number as `round`
+        with StepTraceAnnotation("campaign.chunk", step_num=rnd, rounds=k):
+            with TraceAnnotation("campaign.plan", round=rnd,
+                                 rounds=k) as span:
+                xs_list, recs, key, rng, topo_host = _plan_chunk(
+                    state, scenario, k)
+                span.set_metadata(upload_bytes=tree_nbytes(xs_list))
+            with TraceAnnotation("campaign.carry", round=rnd):
+                carry = _carry_of(state, scenario)
+                if rep is not None:
+                    carry = jax.device_put(carry, rep)
+            with TraceAnnotation("campaign.dispatch", round=rnd), guard:
+                if mode == "scan":
+                    xs = jax.tree.map(lambda *ls: jnp.stack(ls), *xs_list)
+                    carry, ys = fns["scan"](dstack, carry, xs)
+                else:
+                    ys = []
+                    for x in xs_list:
+                        carry, losses = fns["jit_round"](dstack, carry, x)
+                        ys.append(losses)
+                    ys = jnp.stack(ys)
+            # the host waits here for the chunk's device work
+            with TraceAnnotation("campaign.fetch", round=rnd) as span:
+                # ONE host transfer per chunk: the stacked loss history
+                # analysis: sanctioned-sync -- the designed once-per-chunk fetch
+                losses_h = np.asarray(jax.device_get(ys), np.float64)
+                span.set_metadata(bytes=ys.nbytes)
+                for i, rec in enumerate(recs):
+                    rec["loss"] = float(np.mean(losses_h[i]))
+                    history.append(rec)
+                    if log_every and rec["round"] % log_every == 0:
+                        print(f"[round {rec['round']:4d}] "
+                              f"loss={rec['loss']:.4f} lr={rec['lr']:.4f}")
+            with TraceAnnotation("campaign.state", round=rnd):
+                state = _state_of(carry, state, scenario, key, rng, k,
+                                  topo_host)
+            if publish is not None:
+                with TraceAnnotation("campaign.publish", round=rnd):
+                    publish(state.round, state.global_tree)
+            done += k
+            if checkpoint_every:
+                from repro.checkpoint.store import save_state
+                with TraceAnnotation("campaign.checkpoint", round=rnd):
+                    save_state(os.path.join(checkpoint_dir,
+                                            f"round_{state.round:06d}"),
+                               state, scenario)
     return state, history

@@ -81,21 +81,24 @@ def aggregate_hierarchical(groups: Sequence, blur_groups: Sequence = None,
     blur_groups[r] = (N_r,) blur levels. Returns the region-level global
     model. Level-1 weights are computed on each cohort's valid slice, so
     padded (bucketed) cohorts aggregate bit-exactly like unpadded ones."""
-    cohorts = [_as_cohort(g, None if blur_groups is None else b)
-               for g, b in zip(groups, blur_groups or [None] * len(groups))]
-    rsu_models = []
-    rsu_blur = []
-    rsu_count = []
-    for cohort in cohorts:
-        blur = cohort.valid_blur
-        rsu_models.append(cohort_weighted_sum(cohort, flsimco_weights(blur)))
-        rsu_blur.append(blur.mean())
-        rsu_count.append(cohort.n)
-    W = flsimco_weights(jnp.stack(rsu_blur))
-    if count_scaled:
-        W = W * _count_scale(tuple(rsu_count))
-        W = W / jnp.sum(W)
-    return _weighted_tree_sum(rsu_models, W)
+    with jax.named_scope("aggregate"):
+        cohorts = [_as_cohort(g, None if blur_groups is None else b)
+                   for g, b in zip(groups,
+                                   blur_groups or [None] * len(groups))]
+        rsu_models = []
+        rsu_blur = []
+        rsu_count = []
+        for cohort in cohorts:
+            blur = cohort.valid_blur
+            rsu_models.append(cohort_weighted_sum(cohort,
+                                                  flsimco_weights(blur)))
+            rsu_blur.append(blur.mean())
+            rsu_count.append(cohort.n)
+        W = flsimco_weights(jnp.stack(rsu_blur))
+        if count_scaled:
+            W = W * _count_scale(tuple(rsu_count))
+            W = W / jnp.sum(W)
+        return _weighted_tree_sum(rsu_models, W)
 
 
 @functools.lru_cache(maxsize=128)
@@ -357,28 +360,30 @@ def sharded_hierarchical(stacked_trees, blur, mesh, n_rsus: int, *,
         raise ValueError(f"rsu-major cohort of {m} rows not divisible by "
                          f"n_rsus={R}")
     s = m // R
-    if reduction == "psum":
-        accum_name = None if accum_dtype is None \
-            else jnp.dtype(accum_dtype).name
-        # analysis: allow=retrace-fresh-array -- f32 normalization at
-        # the aggregation boundary (no-op when blur is already jnp f32)
-        return _hier_psum_fn(mesh, count_scaled, accum_name)(
-            stacked_trees, jnp.asarray(blur, jnp.float32))
-    # weights exactly as aggregate_hierarchical computes them: per-RSU
-    # level-1 weights on each (s,) blur block, level-2 on the stacked
-    # block means (count-scaled) — all on replicated (tiny) arrays
-    # analysis: allow=retrace-fresh-array -- f32 normalization at the
-    # aggregation boundary (no-op when blur is already jnp f32)
-    blur = jnp.asarray(blur, jnp.float32)
-    blocks = [blur[r * s:(r + 1) * s] for r in range(R)]
-    w1 = jnp.concatenate([flsimco_weights(b) for b in blocks])
-    W2 = flsimco_weights(jnp.stack([b.mean() for b in blocks]))
-    if count_scaled:
-        # cached: same values as jnp.full((R,), s) but not rebuilt per call
-        W2 = W2 * _count_scale((s,) * R)
-        W2 = W2 / jnp.sum(W2)
-    fn = _hier_exact_fn(mesh, agg._resolve_wagg_backend())
-    return fn(stacked_trees, w1, W2)
+    with jax.named_scope("aggregate"):
+        if reduction == "psum":
+            accum_name = None if accum_dtype is None \
+                else jnp.dtype(accum_dtype).name
+            # analysis: allow=retrace-fresh-array -- f32 normalization at
+            # the aggregation boundary (no-op when blur is already jnp f32)
+            return _hier_psum_fn(mesh, count_scaled, accum_name)(
+                stacked_trees, jnp.asarray(blur, jnp.float32))
+        # weights exactly as aggregate_hierarchical computes them: per-RSU
+        # level-1 weights on each (s,) blur block, level-2 on the stacked
+        # block means (count-scaled) — all on replicated (tiny) arrays
+        # analysis: allow=retrace-fresh-array -- f32 normalization at the
+        # aggregation boundary (no-op when blur is already jnp f32)
+        blur = jnp.asarray(blur, jnp.float32)
+        blocks = [blur[r * s:(r + 1) * s] for r in range(R)]
+        w1 = jnp.concatenate([flsimco_weights(b) for b in blocks])
+        W2 = flsimco_weights(jnp.stack([b.mean() for b in blocks]))
+        if count_scaled:
+            # cached: same values as jnp.full((R,), s) but not rebuilt
+            # per call
+            W2 = W2 * _count_scale((s,) * R)
+            W2 = W2 / jnp.sum(W2)
+        fn = _hier_exact_fn(mesh, agg._resolve_wagg_backend())
+        return fn(stacked_trees, w1, W2)
 
 
 def reset_sharded_caches() -> None:

@@ -107,11 +107,13 @@ def motion_blur_kernel(v, camera_const: float = CAMERA_CONST,
 def apply_motion_blur(images, v, camera_const: float = CAMERA_CONST,
                       max_len: int = 9):
     """Blur (B,H,W,C) images with the velocity-dependent horizontal PSF."""
-    k = motion_blur_kernel(v, camera_const, max_len)          # (max_len,)
-    pad = max_len // 2
-    x = jnp.pad(images, ((0, 0), (0, 0), (pad, pad), (0, 0)), mode="edge")
-    # depthwise 1-D conv along W
-    def shift_sum(i):
-        return x[:, :, i:i + images.shape[2], :] * k[i]
-    out = sum(shift_sum(i) for i in range(max_len))
-    return out
+    with jax.named_scope("blur"):
+        k = motion_blur_kernel(v, camera_const, max_len)      # (max_len,)
+        pad = max_len // 2
+        x = jnp.pad(images, ((0, 0), (0, 0), (pad, pad), (0, 0)),
+                    mode="edge")
+
+        # depthwise 1-D conv along W
+        def shift_sum(i):
+            return x[:, :, i:i + images.shape[2], :] * k[i]
+        return sum(shift_sum(i) for i in range(max_len))

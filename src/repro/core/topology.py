@@ -673,7 +673,8 @@ class HandoverMultiRSU(Topology):
 
         new_tree = state.global_tree
         if plan["synced"]:
-            new_tree = agg._weighted_tree_sum(rsu_models, plan["sync_W"])
+            with jax.named_scope("region_sync"):
+                new_tree = agg._weighted_tree_sum(rsu_models, plan["sync_W"])
             rsu_models = [new_tree] * self.n_rsus
         # between syncs global_tree keeps the last merged model; RSU models
         # stay divergent until sync (region_view() merges on demand without

@@ -109,5 +109,6 @@ def dt_loss_fwd_pallas(q, k, tau_alpha: float, tau_beta: float,
         out_specs=[vec_spec] * 4,
         out_shape=out_shape,
         interpret=interpret,
+        name="dt_loss",
     )(q, k)
     return tuple(o[:, 0] for o in outs)

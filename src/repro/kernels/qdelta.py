@@ -89,6 +89,7 @@ def q8_encode_pallas(flat, ef, *, interpret: bool = True,
                    jax.ShapeDtypeStruct((G, N, S), jnp.float32),
                    jax.ShapeDtypeStruct((N, P), jnp.float32)),
         interpret=interpret,
+        name="q8_encode",
     )(flat, ef)
     return codes, _scales_from_tiles(scales), new_ef
 
@@ -108,4 +109,5 @@ def q8_decode_pallas(codes, scales, *, interpret: bool = True,
         out_specs=pl.BlockSpec((N, block), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((N, P), jnp.float32),
         interpret=interpret,
+        name="q8_decode",
     )(codes, _scales_to_tiles(scales, G))

@@ -134,5 +134,6 @@ def rwkv6_pallas(r, k, v, logw, u, *, interpret: bool = True):
         ],
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
         interpret=interpret,
+        name="rwkv6",
     )(rc, kc, vc, lwc, u)
     return o.reshape(BH, S, D), state

@@ -79,4 +79,5 @@ def wagg_pallas(stacked, w, mask=None, *, interpret: bool = True,
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((P,), jnp.float32),
         interpret=interpret,
+        name="wagg",
     )(*operands)

@@ -80,18 +80,19 @@ def _conv(x, w, stride=1):
 
 def _bn(p, s, x, train: bool, momentum=0.9):
     """BatchNorm over NHW. Returns (y, new_state)."""
-    if train:
-        xf = x.astype(jnp.float32)
-        mean = xf.mean(axis=(0, 1, 2))
-        var = xf.var(axis=(0, 1, 2))
-        new_s = {"mean": momentum * s["mean"] + (1 - momentum) * mean,
-                 "var": momentum * s["var"] + (1 - momentum) * var}
-    else:
-        mean, var = s["mean"], s["var"]
-        new_s = s
-    y = (x.astype(jnp.float32) - mean) * jax.lax.rsqrt(var + 1e-5)
-    y = y * p["scale"] + p["bias"]
-    return y.astype(x.dtype), new_s
+    with jax.named_scope("batch_norm"):
+        if train:
+            xf = x.astype(jnp.float32)
+            mean = xf.mean(axis=(0, 1, 2))
+            var = xf.var(axis=(0, 1, 2))
+            new_s = {"mean": momentum * s["mean"] + (1 - momentum) * mean,
+                     "var": momentum * s["var"] + (1 - momentum) * var}
+        else:
+            mean, var = s["mean"], s["var"]
+            new_s = s
+        y = (x.astype(jnp.float32) - mean) * jax.lax.rsqrt(var + 1e-5)
+        y = y * p["scale"] + p["bias"]
+        return y.astype(x.dtype), new_s
 
 
 def resnet_apply(tree, x, train: bool = True):
