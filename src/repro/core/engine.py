@@ -59,10 +59,14 @@ per distinct chunk length (<= 2 for a fixed checkpoint cadence: the
 body chunk + the remainder). The handover topology needs NO extra
 programs and no eager fallback: instead of per-download-group cohorts
 (whose sizes change with vehicle motion), the compiled body gathers
-each client's init model from the stacked per-RSU carry
+each client's init model from a stacked per-RSU model
 (`rsu_stack[down[i]]`) and applies uploads as zero-padded weight
 matrices under `where`-gated sync — regrouping changes DATA, never
-shapes, so one program covers every round regime.
+shapes, so one program covers every round regime. The stack lives only
+inside the program: both callables take and return the per-RSU models
+as the tuple `FLState.topo["rsu_models"]` holds, stack it once on
+entry and unstack it once on exit (in-program copies), so crossing a
+chunk boundary costs the host no per-leaf device op.
 """
 from __future__ import annotations
 
@@ -347,6 +351,10 @@ def _build_cohort_body(scenario):
 def _build_handover_body(scenario):
     """Round body for HandoverMultiRSU: carry = (global_tree, rsu_stack)
     where rsu_stack holds the per-RSU models with a leading n_rsus axis.
+    The stacked form is internal to the compiled program: the callables
+    take and return the per-RSU tuple and convert at their edges
+    (`_stack_rsus` / `_unstack_rsus`), so rounds inside a scan chunk keep
+    the stacked carry.
 
     Every download/upload regrouping arrives as DATA (the per-client
     download index, the zero-padded upload-weight matrix, the sync flag
@@ -413,6 +421,26 @@ def _build_handover_body(scenario):
     return body
 
 
+def _stack_rsus(carry):
+    """(gtree, rsu_models tuple[, ef]) -> (gtree, rsu_stack[, ef]), traced
+    inside the compiled program: one copy of the models, no host op."""
+    gtree, rsus, *ef = carry
+    return (gtree, jax.tree.map(lambda *ls: jnp.stack(ls), *rsus), *ef)
+
+
+def _unstack_rsus(carry):
+    """Inverse of `_stack_rsus`: the per-RSU tuple that
+    `FLState.topo["rsu_models"]` holds."""
+    gtree, rstack, *ef = carry
+    R = jax.tree.leaves(rstack)[0].shape[0]
+    return (gtree, tuple(jax.tree.map(lambda x: x[r], rstack)
+                         for r in range(R)), *ef)
+
+
+def _identity(carry):
+    return carry
+
+
 # --------------------------------------------------------------------------
 # compiled-callable cache
 # --------------------------------------------------------------------------
@@ -438,8 +466,10 @@ def campaign_callables(scenario) -> dict:
     if got is None:
         if isinstance(scenario.topology, HandoverMultiRSU):
             body = _build_handover_body(scenario)
+            enter, leave = _stack_rsus, _unstack_rsus
         else:
             body = _build_cohort_body(scenario)
+            enter = leave = _identity
         # trace counters: jax runs the python function once per trace,
         # and every trace lowers to exactly one XLA program — unlike
         # `fn._cache_size()`, which also counts dispatch-cache re-keys
@@ -453,12 +483,17 @@ def campaign_callables(scenario) -> dict:
                 return f(*a)
             return wrapped
 
+        def _round(ds, c, x):
+            c, losses = body(ds, enter(c), x)
+            return leave(c), losses
+
         def _scan(ds, c, xs):
-            return jax.lax.scan(lambda cc, x: body(ds, cc, x), c, xs)
+            c, ys = jax.lax.scan(lambda cc, x: body(ds, cc, x), enter(c), xs)
+            return leave(c), ys
 
         got = {
             # analysis: allow=retrace-ctor -- memoized in _CALLABLE_CACHE
-            "jit_round": jax.jit(_counted("jit_round", body)),
+            "jit_round": jax.jit(_counted("jit_round", _round)),
             # analysis: allow=retrace-ctor -- memoized in _CALLABLE_CACHE
             "scan": jax.jit(_counted("scan", _scan)),
             "traces": traces,
@@ -495,9 +530,9 @@ def _carry_of(state, scenario):
     # the compiled chunks thread it exactly like the eager rounds do
     ef = (state.comms["ef"],) if codec is not None and codec.stateful else ()
     if isinstance(scenario.topology, HandoverMultiRSU):
-        rstack = jax.tree.map(lambda *ls: jnp.stack(ls),
-                              *state.topo["rsu_models"])
-        return (state.global_tree, rstack) + ef
+        # the per-RSU tuple as the state holds it: the compiled program
+        # stacks it (`_stack_rsus`), the host touches no leaf
+        return (state.global_tree, state.topo["rsu_models"]) + ef
     return (state.global_tree,) + ef
 
 
@@ -507,11 +542,9 @@ def _state_of(carry, state, scenario, key, rng, k, topo_host):
     if codec is not None and codec.stateful:
         carry, comms = carry[:-1], {"ef": carry[-1]}
     if isinstance(scenario.topology, HandoverMultiRSU):
-        gtree, rstack = carry
-        R = scenario.topology.n_rsus
+        gtree, rsu_models = carry
         topo = {"positions": topo_host["positions"],
-                "rsu_models": tuple(
-                    jax.tree.map(lambda x: x[r], rstack) for r in range(R)),
+                "rsu_models": rsu_models,
                 "blur_sum": topo_host["blur_sum"],
                 "upload_count": topo_host["upload_count"]}
         return state.replace(global_tree=gtree, key=key,

@@ -28,9 +28,11 @@ import functools
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis.guards import assert_compile_bounds, track_compiles
 from repro.checkpoint.store import restore_state, save_state
 from repro.core import engine
 from repro.core.clients import CLIENT_UPDATES, raw_local_step
@@ -231,6 +233,103 @@ def test_scan_chunks_compose(case):
     assert [_sans_loss(r) for r in hist6] == \
         [_sans_loss(r) for r in _jit6(case)[1]]
     assert compile_counts(sc)["scan"] <= 2
+
+
+# --------------------------------------------------------------------------
+# handover chunk boundary: the per-RSU tuple crosses it as the state holds it
+# --------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _handover4(mode, codec):
+    """4 handover rounds through run_campaign in chunks of one round."""
+    sc = _scenario("handover", codec=codec)
+    return run_campaign(sc, rounds=4, mode=mode, publish_every=1)
+
+
+def _stacked_boundary_campaign(sc, mode, rounds):
+    """Reference for `_handover4`: the same plan, chunking and round body,
+    with the RSU models stacked per leaf before each chunk and sliced per
+    leaf after it, outside the compiled program."""
+    body = engine._build_handover_body(sc)
+    if mode == "scan":
+        step = jax.jit(lambda ds, c, xs: jax.lax.scan(
+            lambda cc, x: body(ds, cc, x), c, xs))
+    else:
+        step = jax.jit(body)
+    codec = engine._round_codec(sc.cfg)
+    stateful = codec is not None and codec.stateful
+    dstack = engine._data_stack(sc)
+    state = sc.init_state()
+    for _ in range(rounds):
+        xs_list, _, key, rng, topo_host = engine._plan_chunk(state, sc, 1)
+        rstack = jax.tree.map(lambda *ls: jnp.stack(ls),
+                              *state.topo["rsu_models"])
+        carry = (state.global_tree, rstack)
+        if stateful:
+            carry += (state.comms["ef"],)
+        if mode == "scan":
+            xs = jax.tree.map(lambda *ls: jnp.stack(ls), *xs_list)
+        else:
+            xs = xs_list[0]
+        carry, _ = step(dstack, carry, xs)
+        gtree, rstack = carry[:2]
+        rsu_models = tuple(jax.tree.map(lambda x: x[r], rstack)
+                           for r in range(sc.topology.n_rsus))
+        state = state.replace(
+            global_tree=gtree, key=key, host_rng=pack_host_rng(rng),
+            round=state.round + 1,
+            topo={**topo_host, "rsu_models": rsu_models},
+            comms={"ef": carry[2]} if stateful else state.comms)
+    return state
+
+
+@pytest.mark.parametrize("codec", ["identity", "delta_int8"])
+@pytest.mark.parametrize("mode", ["scan", "jit"])
+def test_handover_tuple_carry_matches_stacked_boundary(mode, codec):
+    """The compiled callables take and return the per-RSU tuple and stack
+    it only inside the program: after 4 one-round chunks the RSU models,
+    the global model and the error-feedback residual equal a campaign
+    that stacks and slices the models per leaf around each chunk, bit for
+    bit (the change adds copies only)."""
+    sc = _scenario("handover", codec=codec)
+    st, _ = _handover4(mode, codec)
+    ref = _stacked_boundary_campaign(sc, mode, 4)
+    assert isinstance(st.topo["rsu_models"], tuple)
+    assert len(st.topo["rsu_models"]) == sc.topology.n_rsus
+    for a, b in zip(jax.tree.leaves((st.topo["rsu_models"], st.global_tree)),
+                    jax.tree.leaves((ref.topo["rsu_models"],
+                                     ref.global_tree))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if codec == "delta_int8":
+        np.testing.assert_array_equal(np.asarray(st.comms["ef"]),
+                                      np.asarray(ref.comms["ef"]))
+    else:
+        assert st.comms == ref.comms
+    for k in ("positions", "blur_sum", "upload_count"):
+        np.testing.assert_array_equal(st.topo[k], ref.topo[k])
+
+
+@pytest.mark.parametrize("mode", ["scan", "jit"])
+def test_handover_carry_is_the_state_tuple(mode):
+    """Crossing a chunk boundary costs no device op: the carry holds the
+    very arrays of the state (the RSU models' tuple and the residual),
+    and a warm campaign compiles nothing (no per-shape eager stack or
+    slice program). The codec scenario is this test's and the parity
+    test's alone, so its compile counts are this campaign's."""
+    sc = _scenario("handover", codec="delta_int8")
+    st, _ = _handover4(mode, "delta_int8")
+    carry = engine._carry_of(st, sc)
+    assert carry[0] is st.global_tree
+    assert carry[1] is st.topo["rsu_models"]
+    assert carry[2] is st.comms["ef"]
+    for got, want in zip(jax.tree.leaves(carry[1]),
+                         jax.tree.leaves(st.topo["rsu_models"])):
+        assert got is want
+    with track_compiles() as tracker:
+        st2, _ = run_campaign(sc, rounds=4, mode=mode, publish_every=1)
+    assert tracker.backend_compiles == 0
+    _assert_states_identical(st, st2)
+    assert_compile_bounds(compile_counts(sc))
 
 
 def test_log_every_formats_from_chunk_history(capsys):
