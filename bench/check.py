@@ -7,7 +7,7 @@ round: the global model, or for handover the RSU models. The plain
 reference (reference.py) follows the same rounds. Numbers:
 
 loss1_gap     relative gap of round 1's mean client loss: the blur, the
-              augmentations, both ResNet passes and the DT loss;
+              augmentations, the backbone's passes and the DT loss;
 grad_gap      the first gradient as the optimizer gets it, worked out
               from the model after round 1 (its change over round 1 is
               -lr times the aggregated step): by the worst parameter
@@ -17,7 +17,7 @@ grad_gap      the first gradient as the optimizer gets it, worked out
               whose round-1 gradient in the reference is under a
               thousandth of the median leaf's are left out;
 change_gap    the same for the change over all `check_rounds` rounds,
-              over every leaf (BatchNorm statistics too);
+              over every leaf (state leaves too, where the backbone has any);
 schedule_gap  the largest difference between the velocities and
               learning rates the program recorded and those the
               reference drew: the two sides must train on one schedule.

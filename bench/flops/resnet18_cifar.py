@@ -1,7 +1,7 @@
 """Operations and bytes of ResNet-18-CIFAR training, from the model's shapes.
 
 The shapes come from `jax.eval_shape` of the benchmark's own initialiser
-(`reference.init_model`), so nothing here reads the program. A
+(`init` of `models/resnet18-cifar.py`), so nothing here reads the program. A
 convolution's spatial size follows from the image size and the strides
 (2 on the first block of stages 2-4 and on its 1x1 projection shortcut).
 
@@ -25,9 +25,10 @@ F32 = 4
 
 @functools.lru_cache(maxsize=None)
 def _shapes(model_key):
-    from reference import init_model
+    from reference import backbone
     model = {k: list(v) if isinstance(v, tuple) else v for k, v in model_key}
-    tree = jax.eval_shape(lambda k: init_model(k, model),
+    init = backbone(model["arch"]).init
+    tree = jax.eval_shape(lambda k: init(k, model),
                           jax.ShapeDtypeStruct((2,), jax.numpy.uint32))
     return jax.tree.map(lambda s: tuple(s.shape), tree["params"])
 

@@ -129,6 +129,7 @@ def scenario(cfg: dict, traffic: dict, data, tree, fl_seed: int):
     return Scenario(topology=topo["kind"],
                     topology_kwargs=dict(topo["kwargs"]),
                     client=cfg["client"], aggregator=cfg["aggregator"],
+                    arch=cfg["model"]["arch"],
                     mobility=MobilityModel(**cfg["mobility"]),
                     data=data, global_tree=tree,
                     n_vehicles=cfg["n_vehicles"],

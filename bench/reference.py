@@ -11,8 +11,8 @@ after the draws is written out plainly from the paper and DESIGN.md:
 
 - motion blur of each batch (Eq. 2, horizontal box PSF),
 - the pi1 / pi2 augmentations,
-- ResNet-18 (CIFAR stem, stages 64/128/256/512, BatchNorm with running
-  statistics) and the 512 -> 512 -> 128 projector,
+- the backbone the configuration's `model.arch` names, a module of its
+  own under `models/` (`backbone`), with its projector,
 - the dual-temperature loss (Eq. 6-8) and `local_iters` SGD steps with
   momentum and weight decay,
 - blockwise int8 delta uploads with error feedback (`delta_int8`),
@@ -24,14 +24,18 @@ Every matrix product and convolution runs at the precision the
 configuration states (`matmul_precision`: "default" is one bfloat16
 pass with float32 accumulation on the TPU, "highest" full float32);
 everything else is float32. `dtype=jnp.bfloat16` gives the control: the
-reference computed in bfloat16 throughout (parameters, BatchNorm
-statistics, activations, the SGD step, uploads and aggregation). `fault=` plants one of the faults the
-check has to catch (see `FAULTS`).
+reference computed in bfloat16 throughout (parameters, the backbone's
+state, activations, the SGD step, uploads and aggregation). `fault=`
+plants one of the faults the check has to catch (see `FAULTS`). An 8-bit
+image pool (the configuration's `image_dtype` "uint8") is cast to
+float32 and divided by 255 before the blur.
 """
 from __future__ import annotations
 
 import functools
+import importlib.util
 import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -40,110 +44,34 @@ import numpy as np
 GRAY = (0.299, 0.587, 0.114)
 FAULTS = ("half_batch", "no_exchange")
 Q8_BLOCK = 256
+MODELS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "models")
 
 
 # --------------------------------------------------------------------------
-# model
+# backbones
 # --------------------------------------------------------------------------
 
-def _conv_init(key, kh, kw, cin, cout):
-    std = math.sqrt(2.0 / (kh * kw * cin))
-    return jax.random.normal(key, (kh, kw, cin, cout), jnp.float32) * std
+@functools.lru_cache(maxsize=None)
+def _load(path: str):
+    name = os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location(f"bench_model_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
-def _bn_init(c):
-    return ({"scale": jnp.ones((c,), jnp.float32),
-             "bias": jnp.zeros((c,), jnp.float32)},
-            {"mean": jnp.zeros((c,), jnp.float32),
-             "var": jnp.ones((c,), jnp.float32)})
-
-
-def init_model(key, model: dict):
-    """{"params", "state"} of ResNet-18-CIFAR plus projector, He-normal
-    convolutions, identity BatchNorm. `model` is the configuration's
-    "model" block (stages, widths, projector sizes)."""
-    stages, widths = model["stages"], model["widths"]
-    keys = iter(jax.random.split(key, 64))
-    params, state = {}, {}
-    params["stem"] = _conv_init(next(keys), 3, 3, model["in_channels"],
-                                widths[0])
-    params["stem_bn"], state["stem_bn"] = _bn_init(widths[0])
-    cin = widths[0]
-    for si, (n_blocks, w) in enumerate(zip(stages, widths)):
-        for bi in range(n_blocks):
-            stride = 2 if (bi == 0 and si > 0) else 1
-            blk = {"conv1": _conv_init(next(keys), 3, 3, cin, w),
-                   "conv2": _conv_init(next(keys), 3, 3, w, w)}
-            st = {}
-            blk["bn1"], st["bn1"] = _bn_init(w)
-            blk["bn2"], st["bn2"] = _bn_init(w)
-            if stride != 1 or cin != w:
-                blk["proj"] = _conv_init(next(keys), 1, 1, cin, w)
-                blk["proj_bn"], st["proj_bn"] = _bn_init(w)
-            params[f"s{si}b{bi}"] = blk
-            state[f"s{si}b{bi}"] = st
-            cin = w
-    hid, out = model["projector"]
-    params["proj1"] = (jax.random.normal(next(keys), (cin, hid), jnp.float32)
-                       / math.sqrt(cin))
-    params["proj1_b"] = jnp.zeros((hid,), jnp.float32)
-    params["proj2"] = (jax.random.normal(next(keys), (hid, out), jnp.float32)
-                       / math.sqrt(hid))
-    return {"params": params, "state": state}
-
-
-def _conv(x, w, stride, precision):
-    return jax.lax.conv_general_dilated(
-        x, w.astype(x.dtype), (stride, stride), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=precision)
-
-
-def _bn(p, s, x, momentum=0.9):
-    mean = x.mean(axis=(0, 1, 2))
-    var = jnp.mean(jnp.square(x - mean), axis=(0, 1, 2))
-    y = (x - mean) * jax.lax.rsqrt(var + 1e-5)
-    y = y * p["scale"].astype(x.dtype) + p["bias"].astype(x.dtype)
-    dt = s["mean"].dtype
-    new = {"mean": momentum * s["mean"] + (1 - momentum) * mean.astype(dt),
-           "var": momentum * s["var"] + (1 - momentum) * var.astype(dt)}
-    return y, new
-
-
-def forward(params, state, x, model, precision):
-    """Training-mode forward: (L2-normalised 128-D z in f32, new state)."""
-    stages, widths = model["stages"], model["widths"]
-    ns = {}
-    h = _conv(x, params["stem"], 1, precision)
-    h, ns["stem_bn"] = _bn(params["stem_bn"], state["stem_bn"], h)
-    h = jax.nn.relu(h)
-    cin = widths[0]
-    for si, (n_blocks, w) in enumerate(zip(stages, widths)):
-        for bi in range(n_blocks):
-            name = f"s{si}b{bi}"
-            blk, st = params[name], state[name]
-            stride = 2 if (bi == 0 and si > 0) else 1
-            nb = {}
-            y = _conv(h, blk["conv1"], stride, precision)
-            y, nb["bn1"] = _bn(blk["bn1"], st["bn1"], y)
-            y = jax.nn.relu(y)
-            y = _conv(y, blk["conv2"], 1, precision)
-            y, nb["bn2"] = _bn(blk["bn2"], st["bn2"], y)
-            if "proj" in blk:
-                sc = _conv(h, blk["proj"], stride, precision)
-                sc, nb["proj_bn"] = _bn(blk["proj_bn"], st["proj_bn"], sc)
-            else:
-                sc = h
-            h = jax.nn.relu(y + sc)
-            ns[name] = nb
-            cin = w
-    h = h.mean(axis=(1, 2))
-    z = jax.nn.relu(jnp.dot(h, params["proj1"].astype(h.dtype),
-                            precision=precision)
-                    + params["proj1_b"].astype(h.dtype))
-    z = jnp.dot(z, params["proj2"].astype(z.dtype), precision=precision)
-    z = z.astype(jnp.float32)
-    norm = jnp.sqrt(jnp.sum(z * z, axis=-1, keepdims=True))
-    return z / jnp.maximum(norm, 1e-8), ns
+def backbone(arch: str):
+    """The module `models/<arch>.py`, loaded by path. Each exposes
+    `init(key, model) -> {"params", "state"}` and
+    `forward(params, state, x, model, precision) -> (z, new_state)`:
+    `model` is the configuration's "model" block, `z` the L2-normalised
+    projector output in float32, and `state` (BatchNorm statistics, say)
+    may be an empty dict."""
+    path = os.path.join(MODELS, f"{arch}.py")
+    if not os.path.isfile(path):
+        valid = sorted(f[:-3] for f in os.listdir(MODELS) if f.endswith(".py"))
+        raise SystemExit(f"unknown model arch {arch!r}; valid: {valid}")
+    return _load(path)
 
 
 # --------------------------------------------------------------------------
@@ -245,6 +173,7 @@ def _client_step(model_key, opt_key, dtype_name, half_batch):
     lr_mom, wd, tau_a, tau_b, iters, cam, prec = opt_key
     dtype = jnp.dtype(dtype_name)
     precision = jax.lax.Precision[prec.upper()]
+    forward = backbone(model["arch"]).forward
 
     def loss_fn(params, state, images, key):
         k1, k2 = jax.random.split(key)
@@ -256,6 +185,8 @@ def _client_step(model_key, opt_key, dtype_name, half_batch):
         return dt_loss(q, k, tau_a, tau_b, precision), st2
 
     def step(tree, images, velocity, key, lr):
+        if images.dtype == jnp.uint8:
+            images = images.astype(jnp.float32) / 255.0
         images = motion_blur(images, velocity, cam)
         if half_batch:
             images = images[: images.shape[0] // 2]

@@ -55,6 +55,16 @@ def test_config_file_is_found_by_name(conf):
     assert any(w["config"] == conf["name"] for w in BENCH["workloads"])
 
 
+@pytest.mark.parametrize("conf", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_arch_names_a_backbone_and_a_program_config(conf):
+    from repro.configs.base import get_config
+    cfg = harness.load_json(os.path.join(harness.ROOT, conf["file"]))
+    arch = cfg["model"]["arch"]
+    assert os.path.isfile(os.path.join(harness.BENCH, "models",
+                                       f"{arch}.py"))
+    assert get_config(arch).name == arch
+
+
 @pytest.mark.parametrize("name", WORKLOADS)
 def test_cell_resolves_traffic_limits_and_metrics(name):
     c = harness.cell(BENCH, name)
